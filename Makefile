@@ -95,10 +95,8 @@ docs-check:
 test-kernels:
 	$(PYTHON) -m pytest -x -q tests/test_kernels.py tests/test_paged_qattn.py
 
-# CI entry point: the FULL suite under the pinned jax 0.4.37 (the former
-# test_pipeline/test_roofline exclusions are gone — mesh construction and
-# the HLO cost parser now work against the pinned API).  PYTEST_ARGS lets
-# the workflow deselect the files its fast-signal steps already ran.
+# CI entry point: the FULL suite under jax 0.9.0 on the CPU.  PYTEST_ARGS
+# lets the workflow deselect the files its fast-signal steps already ran.
 test-ci:
 	$(PYTHON) -m pytest -q tests/ $(PYTEST_ARGS)
 

@@ -478,7 +478,7 @@ def run_decode_steady_state():
                                          or "dynamic-slice" in op.name)))
         # mark rows whose kernel ran in interpret mode: their wall-clock and
         # HLO bytes describe the interpreter loop, not the fused TPU kernel
-        interp = kernel and jax.default_backend() != "tpu"
+        interp = kernel and jax.default_backend() == "cpu"
         common.emit(f"fig6.decode_steady.{label}", t,
                     f"hbm_B:{cost.hbm_bytes:.3g};gather_B:{gather_b:.3g}"
                     + (";interpret_mode:1" if interp else ""))

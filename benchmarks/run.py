@@ -7,6 +7,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     from benchmarks import (
         bench_fig3_saliency,
         bench_fig5_retrieval,

@@ -792,8 +792,9 @@ class PagedKVBackend:
     kernel (kernels/paged_qattn): pages are dequantized and consumed in
     place through the page table — no dense (slots, heads, seq, dim) gather
     per step.  Policies the kernel doesn't cover (groupwise/tokenwise
-    stores) silently use the gather+dense fallback, which remains the
-    reference the kernel is verified against (tests/test_paged_qattn.py).
+    stores) are refused at construction; the kernel-off gather+dense path
+    remains the reference the kernel is verified against
+    (tests/test_paged_qattn.py).
 
     Allocator API (`allocator`): "static" pre-assigns every slot its full
     worst case (strided round-robin, pools sized slots x ceil(cap/page));
@@ -814,6 +815,15 @@ class PagedKVBackend:
     pool_fraction: float = 1.0       # freelist pools as a fraction of the
     #                                  static worst case (floor: one full
     #                                  request per segment)
+
+    def __post_init__(self):
+        if self.use_kernel:
+            from repro.kernels import paged_qattn
+            if not paged_qattn.policy_supported(self.ccfg):
+                raise ValueError(
+                    f"the paged decode kernel cannot serve the "
+                    f"{self.ccfg.method!r} policy (it reads channelwise-K / "
+                    f"CST-V or raw stores); use paged_kernel=False")
 
     def init_cache(self, b, h_kv, d, max_len, dtype=jnp.bfloat16, d_v=None):
         """Empty decode cache.  allocator="freelist" returns the elastic
@@ -844,10 +854,8 @@ class PagedKVBackend:
 
     def attend(self, q, cache, scale=None, impl="ref", ctx=None, is_probe=None):
         if self.use_kernel:
-            from repro.kernels import paged_qattn
-            if paged_qattn.kernel_supported(cache):
-                return self.attend_paged(q, cache, scale=scale,
-                                         is_probe=is_probe, impl=impl, ctx=ctx)
+            return self.attend_paged(q, cache, scale=scale,
+                                     is_probe=is_probe, impl=impl, ctx=ctx)
         return kvc.attend_decode(q, cache.dense_view(), scale=scale,
                                  impl=impl, ctx=ctx)
 

@@ -6,13 +6,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_default
 from repro.kernels.cst_quant import kernel as K
 
 EPS = 1e-8
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def cst_quantize(x: jnp.ndarray, bits: int, token_block: int = 256,
@@ -23,7 +20,7 @@ def cst_quantize(x: jnp.ndarray, bits: int, token_block: int = 256,
     channel scales are computed OUTSIDE the kernel (one cheap column reduce);
     the kernel fuses normalize + quantize + pack in a single VMEM pass.
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     *lead, t, ch = x.shape
     xf = x.reshape(-1, t, ch)
     amax = jnp.max(jnp.abs(xf.astype(jnp.float32)), axis=1, keepdims=True)

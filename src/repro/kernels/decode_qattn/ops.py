@@ -20,14 +20,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kvcache as kvc
+from repro.kernels import interpret_default
 from repro.kernels.decode_qattn import kernel as K
 from repro.kernels.decode_qattn import ref as R
 
 NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_slots(store_arrays, block_s):
@@ -71,7 +68,7 @@ def decode_attend_mixed(
     (channelwise K with scale/zero, CST V with token params + channel scale).
     Returns out (b, h, dv).
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     d = q.shape[-1]
     scale = 1.0 / (d ** 0.5)
     stats = []

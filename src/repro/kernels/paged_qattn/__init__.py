@@ -1,1 +1,2 @@
-from repro.kernels.paged_qattn.ops import attend_paged, kernel_supported  # noqa: F401
+from repro.kernels.paged_qattn.ops import (  # noqa: F401
+    attend_paged, kernel_supported, policy_supported)

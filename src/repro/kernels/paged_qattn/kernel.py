@@ -31,9 +31,13 @@ dense reference rounds dequantized values to the store dtype (bf16 in
 serving) before attention lifts them back to f32, so the kernel must round
 identically or its scores drift a bf16 ulp off the gather path's.
 
-TPU note: page-sized blocks below the (8, 128) sublane/lane tile are padded
-by Mosaic; production page sizes (64+) with >=128 packed channels map onto
-full tiles.  CI exercises the kernel in interpret mode on CPU.
+TPU note: Mosaic requires the last two dims of every block to be divisible
+by (8, 128) or to equal the array's.  So the per-page operands and outputs
+carry the page axis OUTSIDE their last two dims (pos (b,npp,1,page), p
+(b,hk,npp,g,page), m_run (b,hk,npp,g,1)) and the wrapper reshapes them;
+packed codes unpack in int32 lanes (Mosaic has no uint8 arithmetic).
+tests/test_tpu_compile.py compiles the kernel for a described v5e chip at
+yi-6b widths; CI runs it in interpret mode on the CPU.
 """
 
 from __future__ import annotations
@@ -46,6 +50,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# f32 operands contract in full f32 on every backend: the TPU's default
+# would round them to bf16, so the chip would not compute what the CPU
+# interpreter (and the gather path's reference) computes
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _unpack(codes, bits, d):
@@ -55,13 +63,13 @@ def _unpack(codes, bits, d):
     if bits >= 16:
         return codes.astype(jnp.float32)
     pf = 8 // bits
+    w = codes.astype(jnp.int32) & 0xFF    # int32 lanes: Mosaic has no u8 math
     if pf == 1:
-        return codes.astype(jnp.uint8).astype(jnp.float32)
-    w = codes.astype(jnp.uint8)
-    mask = jnp.uint8(2**bits - 1)
-    shifts = (jnp.arange(pf, dtype=jnp.uint8) * bits).astype(jnp.uint8)
-    fields = (w[..., None] >> shifts) & mask          # (S, d//pf, pf)
-    return fields.reshape(codes.shape[0], d).astype(jnp.float32)
+        return w.astype(jnp.float32)
+    mask = 2**bits - 1
+    fields = [(w >> (i * bits)) & mask for i in range(pf)]
+    return jnp.stack(fields, axis=-1).reshape(codes.shape[0], d) \
+        .astype(jnp.float32)
 
 
 def _paged_qattn_kernel(tbl_ref,  # scalar prefetch: (b, npp) page table
@@ -86,16 +94,17 @@ def _paged_qattn_kernel(tbl_ref,  # scalar prefetch: (b, npp) page table
         * ks_ref[0, 0, 0].astype(jnp.float32)[None, :]
     if k_bits < 16:  # dense ref rounds dequantized values to the store dtype
         k = k.astype(k_dtype).astype(jnp.float32)
-    s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())))  # (g, page)
-    valid = (pos_ref[0] >= 0)[None, :]                  # (1, page)
+    s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
+                            precision=_F32)                    # (g, page)
+    valid = pos_ref[0, 0] >= 0                          # (1, page)
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.where(valid, jnp.exp(s - m_new), 0.0)       # (g, page)
-    p_out[0, 0] = p                                     # relative to m_new
-    mrun_out[0, 0, 0] = m_new[:, 0]
+    p_out[0, 0, 0] = p                                  # relative to m_new
+    mrun_out[0, 0, 0] = m_new
 
     v = _unpack(vc_ref[0, 0], v_bits, dv)               # (page, dv)
     v = (v - vtz_ref[0, 0].astype(jnp.float32)) * vts_ref[0, 0].astype(jnp.float32)
@@ -105,14 +114,14 @@ def _paged_qattn_kernel(tbl_ref,  # scalar prefetch: (b, npp) page table
 
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32)
+        p, v, precision=_F32, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(j == nj - 1)
     def _fin():
         acc_out[0, 0] = acc_ref[...]
-        m_out[0, 0] = m_ref[...][:, 0]
-        l_out[0, 0] = l_ref[...][:, 0]
+        m_out[0, 0] = m_ref[...]
+        l_out[0, 0] = l_ref[...]
 
 
 @functools.partial(
@@ -163,14 +172,16 @@ def qattn_paged_segment(q, k_pages, k_scale, k_zero, v_pages, v_cscale,
             pl.BlockSpec((1, 1, 1, dv), lambda b_, h_, j, tbl: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, page, 1), lambda b_, h_, j, tbl: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, page, 1), lambda b_, h_, j, tbl: (b_, h_, j, 0)),
-            pl.BlockSpec((1, page), lambda b_, h_, j, tbl: (b_, j)),
+            pl.BlockSpec((1, 1, 1, page), lambda b_, h_, j, tbl: (b_, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, dv), lambda b_, h_, j, tbl: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, g), lambda b_, h_, j, tbl: (b_, h_, 0)),
-            pl.BlockSpec((1, 1, g), lambda b_, h_, j, tbl: (b_, h_, 0)),
-            pl.BlockSpec((1, 1, g, page), lambda b_, h_, j, tbl: (b_, h_, 0, j)),
-            pl.BlockSpec((1, 1, 1, g), lambda b_, h_, j, tbl: (b_, h_, j, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda b_, h_, j, tbl: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, g, 1), lambda b_, h_, j, tbl: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, 1, g, page),
+                         lambda b_, h_, j, tbl: (b_, h_, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, g, 1),
+                         lambda b_, h_, j, tbl: (b_, h_, j, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((g, dv), jnp.float32),
@@ -183,15 +194,17 @@ def qattn_paged_segment(q, k_pages, k_scale, k_zero, v_pages, v_cscale,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hk, g, dv), jnp.float32),
-            jax.ShapeDtypeStruct((b, hk, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hk, g), jnp.float32),
-            jax.ShapeDtypeStruct((b, hk, g, s_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, hk, npp, g), jnp.float32),
+            jax.ShapeDtypeStruct((b, hk, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hk, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hk, npp, g, page), jnp.float32),
+            jax.ShapeDtypeStruct((b, hk, npp, g, 1), jnp.float32),
         ],
         interpret=interpret,
     )(table, q4, k_pages, k_scale, k_zero, v_pages, v_cscale, v_tscale,
-      v_tzero, pos)
-    # expand the per-page running max to per-slot: (b,hk,npp,g)->(b,h,S_pad)
-    mrun_slots = jnp.repeat(jnp.swapaxes(mrun, 2, 3), page, axis=-1)
-    return (acc.reshape(b, h, dv), m.reshape(b, h), l.reshape(b, h),
-            p.reshape(b, h, s_pad), mrun_slots.reshape(b, h, s_pad))
+      v_tzero, pos.reshape(b, npp, 1, page))
+    # pages -> slots: (b,hk,npp,g,page)->(b,h,S_pad); the per-page running
+    # max broadcasts over its page's slots
+    p = jnp.swapaxes(p, 2, 3).reshape(b, h, s_pad)
+    mrun = jnp.broadcast_to(mrun, (b, hk, npp, g, page))
+    mrun = jnp.swapaxes(mrun, 2, 3).reshape(b, h, s_pad)
+    return acc.reshape(b, h, dv), m.reshape(b, h), l.reshape(b, h), p, mrun

@@ -37,19 +37,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import kvcache as kvc
+from repro.kernels import interpret_default
 from repro.kernels.paged_qattn import kernel as K
 from repro.kernels.paged_qattn import ref as R
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def kernel_supported(cache) -> bool:
     """Static check: every non-empty quantized store must be in the ZipCache
     configuration (channelwise K, CST V); raw (bits >= 16) segments always
-    qualify.  Groupwise/tokenwise stores (KIVI/GEAR policies) fall back to
-    the gather+dense path."""
+    qualify.  Groupwise/tokenwise stores (KIVI/GEAR policies) do not."""
     for store in (cache.hi, cache.lo):
         if store.table.shape[1] == 0:
             continue
@@ -63,6 +59,16 @@ def kernel_supported(cache) -> bool:
                     or vm.channel_scale is None:
                 return False
     return True
+
+
+def policy_supported(ccfg) -> bool:
+    """`kernel_supported` for every cache a policy can build, judged on an
+    abstract (shape-only) cache of that policy so it cannot drift from the
+    store layout."""
+    from repro.core import paged
+    cache = jax.eval_shape(
+        lambda: paged.PagedKVBackend(ccfg).init_cache(1, 1, 8, 256))
+    return kernel_supported(cache)
 
 
 def _pad_tokens(x, s_pad, value=0.0):
@@ -164,7 +170,7 @@ def attend_paged(
     contract as `kvc.attend_decode` on the gathered view).
     use_ref=True runs the pure-jnp page-walking oracle instead of Pallas
     (ref.paged_segment_ref) through the identical merge."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     d = q.shape[-1]
     scale = float(scale) if scale is not None else 1.0 / (d ** 0.5)
     segs = []

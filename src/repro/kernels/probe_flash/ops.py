@@ -9,11 +9,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import saliency as sal
+from repro.kernels import interpret_default
 from repro.kernels.probe_flash import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x, mult, axis):
@@ -40,7 +37,7 @@ def probe_flash_attention(
 
     Returns (out (b,h,lq,dv), probe colsum (b,lkv) | None).
     """
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     b, h, lq, d = q.shape
     lkv = k.shape[2]
     bq = min(q_block, max(lq, 8))

@@ -61,10 +61,11 @@ def _extract_operands(rest: str, kind: str) -> List[str]:
     """Operand names of `<shape> kind(<operand list>), attrs...`.
 
     The operand list is the balanced-paren span right after the op kind.
-    Newer jax prints bare names (``dot(%a, %b)``); the pinned 0.4.37 prints
-    inline operand shapes (``dot(f32[128,64]{1,0} %a, ...)``) — so scan to
-    the matching close paren and pull every %name inside, which handles both
-    (attrs like ``calls=%comp`` sit after the close paren and are excluded).
+    HLO text prints operands as bare names (``dot(%a, %b)``) or with inline
+    shapes (``dot(f32[128,64]{1,0} %a, ...)``), depending on the XLA
+    version — so scan to the matching close paren and pull every %name
+    inside, which handles both (attrs like ``calls=%comp`` sit after the
+    close paren and are excluded).
     """
     start = rest.find(kind + "(")
     if start < 0:

@@ -2,11 +2,8 @@
 
 Functions only — importing this module never touches jax device state.
 
-Built on plain `jax.make_mesh(shape, axes)`, which exists unchanged from the
-pinned jax 0.4.37 through current releases.  Axis types are deliberately NOT
-passed: the default (auto sharding on every axis) is what this codebase
-relies on, and `jax.sharding.AxisType` only exists in newer jax — spelling
-it out broke the pin (ROADMAP §Other, fixed).
+Built on plain `jax.make_mesh(shape, axes)`, axis types left at jax's
+default (Explicit in jax 0.9.0).
 """
 
 from __future__ import annotations

@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeConfig
@@ -120,16 +119,15 @@ def pp_forward(params, tokens, cfg: ArchConfig, mesh: Mesh,
         mask = (sidx == S - 1).astype(x_mbs.dtype)
         return jax.lax.psum(outs * mask, "stage")
 
-    # manual over `stage` only; data/model stay GSPMD-auto (shard_map's
-    # `auto` set) so the per-stage layer code keeps its usual TP/DP
-    # shardings (incl. WSC constraints).  The experimental-namespace API is
-    # the one the pinned jax 0.4.37 ships; newer jax aliases it unchanged.
-    y = shard_map(
+    # manual over `stage` only (`axis_names`); data/model stay GSPMD-auto
+    # so the per-stage layer code keeps its usual TP/DP shardings (incl.
+    # WSC constraints)
+    y = jax.shard_map(
         staged, mesh=mesh,
         in_specs=(P("stage"), P()),
         out_specs=P(),
-        check_rep=False,
-        auto=frozenset(mesh.axis_names) - {"stage"},
+        check_vma=False,
+        axis_names={"stage"},
     )(params["groups"], x)
     y = y.reshape(B, L, -1)
     from repro.models import lm

@@ -58,6 +58,7 @@ from repro import configs
 from repro.core.policy import CompressionConfig
 from repro.launch import mesh as mesh_lib
 from repro.models import registry
+from repro.runtime import compile_cache
 from repro.serving import (ContinuousEngine, Request, ServeConfig,
                            ServingEngine, pack_requests)
 
@@ -174,6 +175,11 @@ def validate_engine_args(args, ap: argparse.ArgumentParser,
     is the caller's engine mode (the HTTP front is always continuous)."""
     if args.paged_kernel == "on" and args.backend != "paged":
         ap.error("--paged-kernel on requires --backend paged")
+    if args.paged_kernel == "on":
+        from repro.kernels import paged_qattn
+        if not paged_qattn.policy_supported(build_compression_config(args)):
+            ap.error(f"--paged-kernel on cannot serve --policy {args.policy} "
+                     "(the kernel reads channelwise-K / CST-V or raw stores)")
     if args.scheduler != "fifo" and not continuous:
         ap.error("--scheduler requires --continuous (the lockstep engine "
                  "has no admission queue to schedule)")
@@ -262,6 +268,7 @@ def main(argv=None):
                     help="continuous-batching engine (submit/step/result)")
     args = ap.parse_args(argv)
     validate_engine_args(args, ap, continuous=args.continuous)
+    compile_cache.enable()
 
     cfg = configs.get_arch(args.arch, smoke=args.smoke)
     mesh = None

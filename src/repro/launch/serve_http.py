@@ -23,8 +23,10 @@ here.
 
 ``--replicas N`` runs N engine replicas behind the least-loaded
 `serving.router.EngineRouter` (session affinity via the request's
-``"session"`` field); each replica gets its own slots and page pools, and
-`GET /v1/stats` reports per-replica load.
+``"session"`` field); replica i lives on ``jax.devices()[i]`` with its own
+params, slots and page pools, and `GET /v1/stats` reports per-replica
+load.  N may not exceed the device count (on the CPU,
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` provides N).
 """
 
 from __future__ import annotations
@@ -32,9 +34,12 @@ from __future__ import annotations
 import argparse
 import asyncio
 
+import jax
+
 from repro import configs
 from repro.launch import serve as serve_cli
 from repro.models import registry
+from repro.runtime import compile_cache
 from repro.serving import ContinuousEngine, EngineRouter
 from repro.serving.http import HttpFrontend
 
@@ -45,9 +50,15 @@ def build_frontend(args) -> HttpFrontend:
     cfg = configs.get_arch(args.arch, smoke=args.smoke)
     ccfg = serve_cli.build_compression_config(args)
     scfg = serve_cli.build_serve_config(args)
-    params = registry.materialize_params(cfg, args.seed)
-    replicas = [ContinuousEngine(cfg, ccfg, scfg, params)
-                for _ in range(args.replicas)]
+    devices = jax.devices()
+    if args.replicas > len(devices):
+        raise ValueError(f"--replicas {args.replicas} needs as many devices; "
+                         f"{len(devices)} available")
+    replicas = []
+    for device in devices[:args.replicas]:
+        params = registry.materialize_params(cfg, args.seed, device=device)
+        replicas.append(ContinuousEngine(cfg, ccfg, scfg, params,
+                                         device=device))
     engine = (replicas[0] if args.replicas == 1
               else EngineRouter(replicas))
     return HttpFrontend(engine, host=args.host, port=args.port)
@@ -59,12 +70,12 @@ async def serve(args) -> None:
     print(f"[serve_http] listening on http://{front.host}:{front.port} "
           f"({args.replicas} replica(s), arch={args.arch})")
     try:
-        await asyncio.Event().wait()      # run until interrupted
+        await front.wait()       # until interrupted or an engine step fails
     finally:
-        await front.stop()
+        await front.stop()       # raises a failed step's exception
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     serve_cli.add_engine_args(ap)
     ap.add_argument("--host", default="127.0.0.1",
@@ -74,11 +85,17 @@ def main(argv=None):
     ap.add_argument("--replicas", type=int, default=1,
                     help="engine replicas behind the least-loaded router; "
                          "each owns its own slots and page pools")
+    return ap
+
+
+def main(argv=None):
+    ap = build_parser()
     args = ap.parse_args(argv)
     # the HTTP front always drives the continuous engine
     serve_cli.validate_engine_args(args, ap, continuous=True)
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
+    compile_cache.enable()
     try:
         asyncio.run(serve(args))
     except KeyboardInterrupt:

@@ -66,10 +66,24 @@ def _init_leaf(p: ParamDef, key) -> jnp.ndarray:
     return x.astype(p.dtype)
 
 
-def materialize(schema, seed: int = 0):
+def materialize(schema, seed: int = 0, device=None):
+    """Initialized params, built on `device` (default: the default device).
+
+    One jitted program builds every leaf, so the compiler fuses each leaf's
+    random draw, scaling and cast into the store dtype: no whole-leaf
+    float32 temporary ever exists on the device (a 6B-parameter model would
+    not fit its 16 GB chip otherwise).  Values equal the eager per-leaf
+    build for the same seed."""
     leaves, treedef = jax.tree_util.tree_flatten(schema, is_leaf=is_def)
-    keys = jax.random.split(jax.random.PRNGKey(seed), max(len(leaves), 1))
-    vals = [_init_leaf(p, k) for p, k in zip(leaves, keys)]
+
+    def build(key):
+        keys = jax.random.split(key, max(len(leaves), 1))
+        return [_init_leaf(p, k) for p, k in zip(leaves, keys)]
+
+    out = (None if device is None
+           else jax.sharding.SingleDeviceSharding(device))
+    vals = jax.jit(build, out_shardings=out)(  # retrace: ok(params are built once per process, at setup)
+        jax.random.PRNGKey(seed))
     return jax.tree_util.tree_unflatten(treedef, vals)
 
 

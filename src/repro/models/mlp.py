@@ -135,8 +135,6 @@ def moe_ffn(
             jnp.zeros((), jnp.int32), cap)
         y = y.reshape(b, s, e)
     else:
-        from jax.experimental.shard_map import shard_map
-
         dp = math.prod(mesh.shape[a] for a in data_axes)
         ep = mesh.shape[model_axis]
         e_loc = n_exp // ep
@@ -154,11 +152,11 @@ def moe_ffn(
 
         tok = P(tuple(data_axes), None) if tokens_sharded else P(None, None)
         exp3 = P(model_axis, None, None)
-        y = shard_map(
+        y = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(tok, tok, tok, exp3, exp3, exp3),
             out_specs=tok,
-            check_rep=False,
+            check_vma=False,
         )(x_flat, gates_f, eidx_f, params["w_gate"], params["w_up"], params["w_down"])
         y = y.reshape(b, s, e)
 

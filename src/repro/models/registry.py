@@ -24,8 +24,8 @@ def abstract_params(cfg: ArchConfig):
     return common.abstract(schema(cfg))
 
 
-def materialize_params(cfg: ArchConfig, seed: int = 0):
-    return common.materialize(schema(cfg), seed)
+def materialize_params(cfg: ArchConfig, seed: int = 0, device=None):
+    return common.materialize(schema(cfg), seed, device=device)
 
 
 def param_logical_specs(cfg: ArchConfig):

@@ -17,7 +17,7 @@ same tokens, just 100x slower.  This module makes it assertable:
 Implementation: `jax.monitoring` fires a
 ``/jax/core/compile/backend_compile_duration`` event exactly once per
 actual backend (XLA) compilation — jit-cache hits fire nothing (verified
-against the pinned jax 0.4.37).  One process-wide listener is registered
+against jax 0.9.0).  One process-wide listener is registered
 lazily and fans out to the currently-active logs, so nested/overlapping
 guards each see every compile in their window.  For human-readable
 diagnostics the guard also flips ``jax_log_compiles`` inside the context
@@ -39,7 +39,7 @@ from typing import Iterator, List, Set
 import jax
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# jax 0.4.37 emits per-program trace/lower/compile messages on these
+# jax emits per-program trace/lower/compile messages on these
 # loggers when jax_log_compiles is on
 _LOG_SOURCES = ("jax._src.dispatch", "jax._src.interpreters.pxla",
                 "jax._src.pjit")
@@ -54,6 +54,7 @@ class CompileLog:
 
     def __init__(self) -> None:
         self.count = 0
+        self.seconds = 0.0               # backend compile time, summed
         self.names: List[str] = []       # best-effort program names
 
     def describe(self) -> str:
@@ -71,12 +72,13 @@ def _on_event(event: str, duration: float = 0.0, **kw) -> None:
     with _lock:
         for log in _active:
             log.count += 1
+            log.seconds += duration
 
 
 def _ensure_listener() -> None:
-    """Register the process-wide monitoring listener once.  jax 0.4.37 has
-    no public unregister, so the listener stays installed and fans out to
-    whatever logs are active (none, outside any guard)."""
+    """Register the process-wide monitoring listener once.  It stays
+    installed and fans out to whatever logs are active (none, outside any
+    guard), so overlapping guards never race an unregister."""
     global _listener_installed
     with _lock:
         if _listener_installed:

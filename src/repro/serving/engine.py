@@ -47,7 +47,9 @@ The jitted programs:
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
+import functools
 import itertools
 import time
 from typing import (Callable, Deque, Dict, Iterator, List, Optional, Sequence,
@@ -311,7 +313,9 @@ def pack_requests(requests: Sequence[np.ndarray], batch_size: int,
 
 def _sample_tokens(logits: jnp.ndarray, temps: jnp.ndarray,
                    seeds: jnp.ndarray, counters: jnp.ndarray) -> jnp.ndarray:
-    """Per-slot greedy/temperature sampling, (b, vocab) -> (b,) int32.
+    """Per-slot greedy/temperature sampling, (b, vocab) -> (2, b) int32:
+    row 0 the sampled tokens, row 1 whether each row's logits were all
+    finite (rides the same readback, feeds `EngineCore.nonfinite_logits`).
 
     Keys derive from (request seed, token counter) so a request's sample
     stream is independent of its slot index and admission step.
@@ -321,7 +325,8 @@ def _sample_tokens(logits: jnp.ndarray, temps: jnp.ndarray,
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits.astype(jnp.float32) / jnp.maximum(temps, 1e-3)[:, None]
     sampled = jax.vmap(jax.random.categorical)(keys, scaled).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy)
+    finite = jnp.all(jnp.isfinite(logits), axis=-1).astype(jnp.int32)
+    return jnp.stack([jnp.where(temps > 0, sampled, greedy), finite])
 
 
 # ---------------------------------------------------------------------------
@@ -352,13 +357,32 @@ def _unpack_step_inputs(packed: jnp.ndarray):
 # Shared jitted-program bundle
 # ---------------------------------------------------------------------------
 
+def _on_device(method):
+    """Run an engine method with the engine's device as JAX's default, so
+    every host upload it makes (prompts, staging matrices, slot ids) and
+    every array it creates lands on that device, not on device 0."""
+    @functools.wraps(method)
+    def placed(self, *args, **kwargs):
+        with self._placement():
+            return method(self, *args, **kwargs)
+    return placed
+
+
 class _EngineBase:
     def __init__(self, cfg: ArchConfig, ccfg: CompressionConfig, scfg: ServeConfig,
-                 params, mesh=None):
+                 params, mesh=None, device=None):
+        if device is not None and mesh is not None:
+            raise ValueError("an engine is placed on a mesh or on one "
+                             "device, not both")
         self.cfg = cfg
         self.ccfg = ccfg
         self.scfg = scfg
-        self.params = params
+        # device: the one chip this engine replica serves from (None = the
+        # default device, or the mesh).  Params are committed there, so
+        # every jitted program runs there.
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         shape = ShapeConfig("serve", scfg.prompt_len, scfg.batch_size, "prefill",
                             cache_backend=scfg.backend, page_size=scfg.page_size,
                             paged_kernel=scfg.paged_kernel,
@@ -432,6 +456,10 @@ class _EngineBase:
                     steps_lib.make_recompress_slot_step(
                         cfg, shape, mesh, ccfg, ctx=self.ctx, ladder=True)[0])
         self._sample = jax.jit(_sample_tokens)
+
+    def _placement(self):
+        return (contextlib.nullcontext() if self.device is None
+                else jax.default_device(self.device))
 
     # ------------------------------------------------------------------
     def _bucket_len(self, n_tokens: int) -> int:
@@ -558,7 +586,8 @@ class EngineCore(_EngineBase):
     """
 
     def __init__(self, cfg: ArchConfig, ccfg: CompressionConfig, scfg: ServeConfig,
-                 params, scheduler: scheduler_lib.Scheduler, mesh=None):
+                 params, scheduler: scheduler_lib.Scheduler, mesh=None,
+                 device=None):
         if cfg.encdec or cfg.frontend != "none":
             raise NotImplementedError(
                 "ContinuousEngine currently serves decoder-only text models; "
@@ -573,8 +602,9 @@ class EngineCore(_EngineBase):
                 "ContinuousEngine does not yet support MoE archs: expert "
                 "capacity is shared across batch rows, so inactive slots are "
                 "not isolated; use the lockstep ServingEngine")
-        super().__init__(cfg, ccfg, scfg, params, mesh)
-        self.caches = registry.init_caches(cfg, self.ctx, scfg.batch_size)
+        super().__init__(cfg, ccfg, scfg, params, mesh, device)
+        with self._placement():
+            self.caches = registry.init_caches(cfg, self.ctx, scfg.batch_size)
         self._free_slot = jax.jit(registry.free_caches)
         self._unstage = jax.jit(_unpack_step_inputs)
         self.scheduler = scheduler
@@ -587,6 +617,9 @@ class EngineCore(_EngineBase):
         self._known: Set[str] = set()      # every id ever submitted here
         self._closed = False
         self._token_log: Dict[str, List[int]] = {}   # feeds stream()
+        # live rows sampled from logits holding a NaN/inf (a health
+        # counter: 0 on a correct model and device)
+        self.nonfinite_logits = 0
         self._events: List[events_lib.Event] = []    # current step's events
         # Elastic page allocation (core/alloc.py): host-side free lists +
         # page tables, synced onto the device cache tree between jitted
@@ -824,6 +857,7 @@ class EngineCore(_EngineBase):
                 return
             self.step()
 
+    @_on_device
     def cancel(self, request_id: str, reason: str = "client") -> bool:
         """Retire a queued or running request early (client disconnect,
         expired deadline, or an explicit API call).
@@ -927,6 +961,7 @@ class EngineCore(_EngineBase):
     # scheduler internals
     # ------------------------------------------------------------------
 
+    @_on_device
     def _sync_tables(self) -> None:
         """Install the allocator's current page tables onto the device cache
         tree (values only — shapes never change, so no jitted program
@@ -972,6 +1007,7 @@ class EngineCore(_EngineBase):
             stats["swap"] = self._swap.stats()
         return stats
 
+    @_on_device
     def free(self, slot_id: int) -> None:
         """Retire a slot: invalidate its batch row (cheap row writes; stale
         codes are masked by pos == -1 until the next insert overwrites
@@ -1271,9 +1307,10 @@ class EngineCore(_EngineBase):
             temp = jnp.asarray([req.sampling.temperature], jnp.float32)  # sync: ok(admission-time one-shot sample input)
             seed = jnp.asarray([req.sampling.seed], jnp.int32)  # sync: ok(admission-time one-shot sample input)
             ctr = jnp.asarray([0], jnp.int32)  # sync: ok(admission-time one-shot sample input)
-            first = int(np.asarray(  # sync: ok(admission-time readback of the first sampled token)
-                self._sample(logits, temp, seed, ctr))[0])
-            generated = [first]
+            first, finite = np.asarray(  # sync: ok(admission-time readback of the first sampled token)
+                self._sample(logits, temp, seed, ctr))[:, 0]
+            self.nonfinite_logits += int(not finite)
+            generated = [int(first)]
         else:
             # the first token was sampled at the ORIGINAL admission; the
             # prefill above rebuilt exactly the cache it was sampled from
@@ -1592,6 +1629,7 @@ class EngineCore(_EngineBase):
             self._sync_tables()
         return freed
 
+    @_on_device
     def step(self) -> List[events_lib.Event]:
         """One scheduler iteration: run the injected scheduler's admission
         plan (and preemptions), decode one token for every active slot,
@@ -1642,8 +1680,10 @@ class EngineCore(_EngineBase):
 
         logits, self.caches = self._decode_masked(
             self.params, self.caches, tok, probes, act)
-        nxt = np.asarray(  # sync: ok(the single batched device->host token read per step)
+        nxt, finite = np.asarray(  # sync: ok(the single batched device->host token read per step)
             self._sample(logits, temps, seeds, counters))
+        self.nonfinite_logits += int(  # sync: ok(host numpy: the readback above)
+            np.sum(finite[active_ids] == 0))
 
         due = []
         for i in active_ids:
@@ -1677,7 +1717,8 @@ class ContinuousEngine(EngineCore):
 
     def __init__(self, cfg: ArchConfig, ccfg: CompressionConfig, scfg: ServeConfig,
                  params, mesh=None,
-                 scheduler: Optional[scheduler_lib.Scheduler] = None):
+                 scheduler: Optional[scheduler_lib.Scheduler] = None,
+                 device=None):
         super().__init__(cfg, ccfg, scfg, params,
                          scheduler or scheduler_lib.make_scheduler(scfg.scheduler),
-                         mesh=mesh)
+                         mesh=mesh, device=device)

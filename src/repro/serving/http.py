@@ -34,7 +34,11 @@ Drive loop
     request deferred by the page pools, or nothing pending) the loop backs
     off exponentially (`Backoff`) instead of busy-driving ``step()`` the
     way the synchronous ``stream()`` helper may; a fresh submit wakes it
-    immediately (``_wake``).
+    immediately (``_wake``).  A step that RAISES (a compile error, device
+    out-of-memory) ends the loop: every waiting handler is failed (500 /
+    an SSE ``event: error``), later submits get 503, and ``stop()`` raises
+    the step's exception — a broken engine never leaves clients hanging
+    or a server process exiting 0.
 
 Cancellation
     While an SSE response is open the handler also watches the client
@@ -100,6 +104,10 @@ _SSE_HEADER = (b"HTTP/1.1 200 OK\r\n"
                b"Connection: close\r\n\r\n")
 
 
+def _error_payload(error: BaseException) -> Dict:
+    return {"error": f"engine step failed: {type(error).__name__}: {error}"}
+
+
 def _sse(payload, event: Optional[str] = None) -> bytes:
     head = f"event: {event}\n" if event else ""
     return f"{head}data: {json.dumps(payload)}\n\n".encode()
@@ -131,6 +139,7 @@ class HttpFrontend:
         self._server: Optional[asyncio.AbstractServer] = None
         self._drive_task: Optional[asyncio.Task] = None
         self._closed = False
+        self._error: Optional[BaseException] = None   # a failed step()
 
     # ------------------------------------------------------------------
     # drive loop: the ONLY caller of engine.step() while the front is up
@@ -160,13 +169,27 @@ class HttpFrontend:
                     await asyncio.wait_for(self._wake.wait(),
                                            timeout=self.backoff.maximum)
                 continue
-            if self._drive_once():
+            try:
+                progressed = self._drive_once()
+            except Exception as e:  # noqa: BLE001 — surfaced by stop()
+                self._fail(e)
+                return
+            if progressed:
                 await asyncio.sleep(0)      # yield: let handlers flush SSE
             else:
                 # pending but no events: every queued request is deferred
                 # (page-pool pressure) — back off instead of spinning the
                 # scheduler at CPU speed
                 await asyncio.sleep(self.backoff.next_delay())
+
+    def _fail(self, error: BaseException) -> None:
+        """The engine's step raised: stop driving, fail every waiting
+        handler (each queue gets the exception as its terminal item), and
+        keep the error for `stop()` to raise."""
+        self._error = error
+        self._closed = True
+        for q in self._queues.values():
+            q.put_nowait(error)
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -178,10 +201,17 @@ class HttpFrontend:
         self.port = self._server.sockets[0].getsockname()[1]
         self._drive_task = asyncio.create_task(self._drive())
 
+    async def wait(self) -> None:
+        """Serve until `stop()` or a failed engine step ends the drive
+        loop (then `stop()` raises that step's exception)."""
+        if self._drive_task is not None:
+            await asyncio.shield(self._drive_task)
+
     async def stop(self, drain: bool = True) -> None:
         """Stop serving.  drain=True also closes the engine (`shutdown()`)
         and steps it until every accepted request finished; drain=False
-        detaches and leaves the engine open for reuse."""
+        detaches and leaves the engine open for reuse.  Raises the
+        exception of a failed engine step, after closing the server."""
         self._closed = True
         self._wake.set()
         if self._drive_task is not None:
@@ -191,6 +221,8 @@ class HttpFrontend:
             self._server.close()
             await self._server.wait_closed()
             self._server = None
+        if self._error is not None:
+            raise self._error
         if drain:
             self.engine.shutdown()
             while self.engine.pending:
@@ -309,6 +341,8 @@ class HttpFrontend:
             await writer.drain()
             return
         queue: asyncio.Queue = asyncio.Queue()
+        if self._error is not None:
+            queue.put_nowait(self._error)   # accepted after the failure
         self._queues[rid] = queue
         self._wake.set()
         try:
@@ -330,6 +364,11 @@ class HttpFrontend:
                             writer: asyncio.StreamWriter) -> None:
         while True:
             ev = await queue.get()
+            if isinstance(ev, BaseException):
+                writer.write(_json_response(
+                    "500 Internal Server Error", _error_payload(ev)))
+                await writer.drain()
+                return
             if isinstance(ev, _TERMINAL):
                 break
         writer.write(_json_response("200 OK", self._final_payload(rid)))
@@ -355,6 +394,12 @@ class HttpFrontend:
                     self._cancel_quietly(rid, "client")
                     return
                 ev = getter.result()
+                if isinstance(ev, BaseException):
+                    with contextlib.suppress(
+                            ConnectionResetError, BrokenPipeError):
+                        writer.write(_sse(_error_payload(ev), event="error"))
+                        await writer.drain()
+                    return
                 if isinstance(ev, events_lib.TokenEvent):
                     try:
                         writer.write(_sse(

@@ -503,6 +503,47 @@ def test_http_cancel_endpoint(engine):
     assert final["finish_reason"] == "cancelled"
 
 
+def test_http_failed_step_fails_clients_and_stop_raises(engine,
+                                                        monkeypatch):
+    """A step that raises (a compile error, device OOM) must not strand
+    its waiting clients or let the server exit cleanly: the SSE client gets
+    an error frame, the JSON client a 500, a later submit too, and
+    `stop()` raises the step's exception."""
+    cfg, eng = engine
+
+    def broken_step():
+        raise RuntimeError("device out of memory")
+
+    monkeypatch.setattr(eng, "step", broken_step)
+
+    async def run():
+        front = HttpFrontend(eng, port=0)
+        await front.start()
+        reader, writer = await _open_post(
+            front.port, "/v1/generate", {"tokens": _prompt(cfg, seed=5)})
+        status = await _read_headers(reader)
+        frames = (await reader.read()).decode()
+        writer.close()
+        failed_json = await _request_json(
+            front.port, "POST", "/v1/generate",
+            {"tokens": _prompt(cfg, seed=6), "stream": False})
+        await front.wait()                 # the drive loop has ended
+        with pytest.raises(RuntimeError, match="out of memory"):
+            await front.stop(drain=False)
+        return status, frames, failed_json
+
+    status, frames, failed_json = asyncio.run(run())
+    assert "200" in status and "event: error" in frames
+    assert "out of memory" in frames
+    assert "500" in failed_json[0] and "out of memory" in failed_json[1]["error"]
+    monkeypatch.undo()
+    # the module engine still holds the two submitted requests: retire them
+    for rid in set(eng._known) - set(eng.results):
+        eng.cancel(rid)
+    while eng.pending:
+        eng.step()
+
+
 def test_http_router_two_replicas_end_to_end(engine):
     """Two REAL engine replicas behind the router, served over HTTP:
     session-less requests spread by load, every stream stays bitwise its
@@ -600,3 +641,61 @@ def test_serve_http_accepts_continuous_only_combos(monkeypatch):
                  "--scheduler", "priority", "--preemption", "recompute"])
     assert captured["args"].pool_fraction == 0.5
     assert captured["args"].replicas == 1
+
+
+_REPLICA_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json
+import jax
+import numpy as np
+from repro.launch import serve_http
+from repro.serving import Request
+
+ap = serve_http.build_parser()
+args = ap.parse_args(["--arch", "yi-6b", "--smoke", "--backend", "paged",
+                      "--page-allocator", "freelist", "--batch", "2",
+                      "--prompt-len", "16", "--max-new", "4",
+                      "--replicas", "2"])
+router = serve_http.build_frontend(args).engine
+rng = np.random.default_rng(0)
+for i in range(4):
+    router.submit(Request(tokens=rng.integers(2, 200, size=(12,))))
+while router.pending:
+    router.step()
+placed = []
+for eng in router.replicas:
+    trees = (eng.params, eng.caches)
+    placed.append(sorted({str(d) for t in trees
+                          for x in jax.tree_util.tree_leaves(t)
+                          for d in x.devices()}))
+print("RESULT:" + json.dumps({"placed": placed,
+                              "devices": [str(d) for d in jax.devices()]}))
+"""
+
+
+def test_serve_http_replicas_each_on_own_device():
+    """`--replicas N` puts replica i on jax.devices()[i] — its params AND
+    its caches, still there after serving (host uploads follow the
+    replica); none falls back to the default device.  Subprocess: the
+    device-count flag must be set before jax initializes."""
+    import os
+    import subprocess
+    import sys
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    proc = subprocess.run([sys.executable, "-c", _REPLICA_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
+    assert line, proc.stderr[-3000:]
+    out = json.loads(line[0][len("RESULT:"):])
+    assert out["placed"] == [[out["devices"][0]], [out["devices"][1]]]
+
+
+def test_serve_http_refuses_more_replicas_than_devices():
+    import jax
+    from repro.launch import serve_http
+    ap = serve_http.build_parser()
+    args = ap.parse_args(["--arch", "yi-6b", "--smoke",
+                          "--replicas", str(len(jax.devices()) + 1)])
+    with pytest.raises(ValueError, match="devices"):
+        serve_http.build_frontend(args)

@@ -102,3 +102,20 @@ def test_full_param_counts_sane():
         cfg = configs.get_arch(arch)
         n = common.count_params(registry.schema(cfg)) / 1e9
         assert lo < n < hi, (arch, n)
+
+
+@pytest.mark.parametrize("arch", ["yi-6b", "deepseek-v2-lite-16b"])
+def test_jitted_materialize_matches_eager(arch):
+    """`common.materialize` builds all leaves in one jitted program (no
+    whole-leaf float32 temporary on the device); the values must be the
+    eager per-leaf build's, bit for bit, for the same seed."""
+    from repro.models import common
+    schema = registry.schema(configs.get_arch(arch, smoke=True))
+    leaves = jax.tree_util.tree_leaves(schema, is_leaf=common.is_def)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    eager = [common._init_leaf(p, k) for p, k in zip(leaves, keys)]
+    built = jax.tree_util.tree_leaves(common.materialize(schema, 7))
+    assert len(built) == len(eager)
+    for a, b in zip(built, eager):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

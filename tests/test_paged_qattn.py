@@ -144,9 +144,10 @@ def test_paged_kernel_bf16_store_rounding_matches_dense(rng):
 
 
 def test_backend_attend_dispatch_and_fallback(rng):
-    """use_kernel=True routes supported caches through the kernel and falls
-    back to gather+dense for unsupported quantization schemes (KIVI's
-    groupwise stores) — same outputs either way, no crash."""
+    """use_kernel=True routes supported caches through the kernel (same
+    outputs as the gather path), and a policy the kernel cannot serve
+    (KIVI's groupwise stores, MiKV's tokenwise values) is refused when the
+    backend is built — never silently served by the gather path."""
     hk, d, l = 2, 16, 40
     k = jnp.asarray(rng.normal(size=(2, hk, l, d)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(2, hk, l, d)).astype(np.float32))
@@ -162,13 +163,14 @@ def test_backend_attend_dispatch_and_fallback(rng):
                                np.asarray(be_off.attend(q, cache).out),
                                atol=1e-5, rtol=1e-5)
 
-    kivi = backend_lib.of(_ccfg("kivi"), kind="paged", page_size=8,
-                          paged_kernel=True)
-    cache_g = kivi.compress_prefill(k, v, None, 56, dtype=jnp.float32)
+    for policy in ("kivi", "mikv"):
+        assert not pq_ops.policy_supported(_ccfg(policy))
+        with pytest.raises(ValueError, match="cannot serve"):
+            backend_lib.of(_ccfg(policy), kind="paged", page_size=8,
+                           paged_kernel=True)
+    cache_g = backend_lib.of(_ccfg("kivi"), kind="paged", page_size=8) \
+        .compress_prefill(k, v, None, 56, dtype=jnp.float32)
     assert not pq_ops.kernel_supported(cache_g)
-    ref = backend_lib.of(_ccfg("kivi"), kind="paged", page_size=8)
-    np.testing.assert_array_equal(np.asarray(kivi.attend(q, cache_g).out),
-                                  np.asarray(ref.attend(q, cache_g).out))
 
     with pytest.raises(ValueError):
         backend_lib.of(_ccfg(), kind="mixed", paged_kernel=True)

@@ -31,7 +31,8 @@ REQUIRED = ("kernel.py", "ref.py", "ops.py")
 def _has_interpret_kwarg(path: Path) -> bool:
     """Does the file mention an `interpret` keyword (in a call or a
     function signature)?  The dispatch idiom is
-    `interpret = (not _on_tpu()) if interpret is None else interpret`."""
+    `interpret = interpret_default() if interpret is None else interpret`
+    (`repro.kernels.interpret_default`: interpret on the CPU backend only)."""
     tree = ast.parse(path.read_text(), filename=str(path))
     for node in ast.walk(tree):
         if isinstance(node, ast.Call):
