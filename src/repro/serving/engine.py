@@ -66,6 +66,7 @@ from repro.core import swap as swap_lib
 from repro.core.policy import CompressionConfig
 from repro.launch import steps as steps_lib
 from repro.models import registry
+from repro.runtime import tracing
 from repro.serving import events as events_lib
 from repro.serving import scheduler as scheduler_lib
 
@@ -620,6 +621,10 @@ class EngineCore(_EngineBase):
         # live rows sampled from logits holding a NaN/inf (a health
         # counter: 0 on a correct model and device)
         self.nonfinite_logits = 0
+        # cumulative work counters (`counters()`); each `engine.step` span
+        # carries them as they stand when the step begins
+        self._counts = dict.fromkeys(self.COUNTERS, 0)
+        tracing.install_gc_span()
         self._events: List[events_lib.Event] = []    # current step's events
         # Elastic page allocation (core/alloc.py): host-side free lists +
         # page tables, synced onto the device cache tree between jitted
@@ -972,19 +977,33 @@ class EngineCore(_EngineBase):
             return
         from repro.core import paged as paged_lib
 
-        t = self._alloc.tables()
-        # upload each (slots, npp) table ONCE and share the device array
-        # across all cache elements — with_tables broadcasts device-side
-        jt = {k: jnp.asarray(v, jnp.int32)  # sync: ok(three small table uploads per allocator mutation, shared across elements)
-              for k, v in t.items()}
-        is_paged = lambda x: isinstance(x, paged_lib.PagedKVCache)
-        leaves, treedef = jax.tree_util.tree_flatten(
-            self.caches, is_leaf=is_paged)
-        self.caches = jax.tree_util.tree_unflatten(
-            treedef,
-            [paged_lib.with_tables(el, jt["hi"], jt["lo"], jt["win"])
-             if is_paged(el) else el for el in leaves])
-        self._alloc.dirty = False
+        with tracing.span("engine.sync_tables"):
+            t = self._alloc.tables()
+            # upload each (slots, npp) table ONCE and share the device array
+            # across all cache elements — with_tables broadcasts device-side
+            jt = {k: jnp.asarray(v, jnp.int32)  # sync: ok(three small table uploads per allocator mutation, shared across elements)
+                  for k, v in t.items()}
+            is_paged = lambda x: isinstance(x, paged_lib.PagedKVCache)
+            leaves, treedef = jax.tree_util.tree_flatten(
+                self.caches, is_leaf=is_paged)
+            self.caches = jax.tree_util.tree_unflatten(
+                treedef,
+                [paged_lib.with_tables(el, jt["hi"], jt["lo"], jt["win"])
+                 if is_paged(el) else el for el in leaves])
+            self._alloc.dirty = False
+
+    COUNTERS = ("steps", "probe_steps", "probe_rows", "folds",
+                "fold_events", "admissions", "prefill_tokens")
+
+    def counters(self) -> Dict[str, int]:
+        """Cumulative work counts since construction, served as `engine` by
+        `/v1/stats`: `steps` (steps that decoded), `probe_steps` (of them,
+        steps on which a live row probed), `probe_rows` (probing rows
+        summed over steps), `folds` (slot windows folded) in `fold_events`
+        (fold calls), `admissions` (prefills, prefix hits, swap-ins and
+        recompute re-admissions), `prefill_tokens` (bucket tokens run
+        through prefill)."""
+        return dict(self._counts)
 
     def pool_stats(self) -> Optional[Dict]:
         """Free-list pool telemetry (None for static/mixed layouts):
@@ -1174,7 +1193,10 @@ class EngineCore(_EngineBase):
                                         self._pool_view())
             for slot_id, req in plan.admissions:
                 self.queue.remove(req)
-                self._admit_one(slot_id, req)
+                self._counts["admissions"] += 1
+                with tracing.span("engine.prefill", request_id=req.id,
+                                  prompt_tokens=len(req.tokens)):
+                    self._admit_one(slot_id, req)
             if (self.scfg.preemption in ("recompute", "downshift", "swap")
                     and self.queue and n_evicted < self.scfg.batch_size):
                 victim = self.scheduler.select_victim(
@@ -1278,6 +1300,7 @@ class EngineCore(_EngineBase):
             self._sync_tables()
         else:
             prompt = pack_requests([req.tokens], 1, bucket)
+            self._counts["prefill_tokens"] += bucket
             logits, slice_caches = self._prefill_for(bucket)(
                 self.params,
                 {"tokens": jnp.asarray(prompt)})  # sync: ok(the prompt upload itself — once per admission, not per step)
@@ -1575,6 +1598,8 @@ class EngineCore(_EngineBase):
         map's bits — so one warm program per signature covers pressured
         and unpressured folds alike (tests/test_retrace.py)."""
         b = self.scfg.batch_size
+        self._counts["fold_events"] += 1
+        self._counts["folds"] += len(due_ids)
         if self._alloc is not None:
             # CoW-before-fold: recompression re-splits hi/lo per slot, so a
             # slot still aliasing shared-prefix pages must be privatized
@@ -1646,63 +1671,85 @@ class EngineCore(_EngineBase):
         Events are DRAINED at return, not reset at entry: a `cancel()`
         issued between steps (an async server loop reacting to a
         disconnect) buffers its `CancelledEvent` into the next step's
-        return value instead of being dropped."""
-        self._sweep_deadlines()
-        self._ladder_step()   # relieve pool pressure BEFORE planning
-        self._admit()         # admission, so freed pages count this step
-        b = self.scfg.batch_size
-        active_ids = [i for i in range(b) if self.slots[i] is not None]
-        if not active_ids:
+        return value instead of being dropped.
+
+        Host spans (`runtime/tracing`): `engine.step` around the whole
+        iteration, carrying `counters()` as they stand at its start, and
+        inside it `engine.admit`, `engine.prefill` (per admission),
+        `engine.stage`, `engine.sync_tables`, `engine.decode`,
+        `engine.readback` (the host's one wait on the device),
+        `engine.emit` and `engine.fold`."""
+        n = self._counts
+        with tracing.span("engine.step", **n):
+            with tracing.span("engine.admit"):
+                self._sweep_deadlines()
+                self._ladder_step()   # relieve pool pressure BEFORE planning
+                self._admit()         # admission, so freed pages count this step
+            b = self.scfg.batch_size
+            active_ids = [i for i in range(b) if self.slots[i] is not None]
+            if not active_ids:
+                events, self._events = self._events, []
+                return events
+            n["steps"] += 1
+            interval = self.ccfg.recompress_interval
+            with tracing.span("engine.stage"):
+                if self._alloc is not None:
+                    for i in active_ids:
+                        self._alloc.note_append(i)
+                    self._sync_tables()
+                # all per-slot scalars ride ONE (6, b) staging matrix: a
+                # single host->device transfer per step instead of six (the
+                # hostsync lint flags per-scalar churn; values/dtypes are
+                # bit-identical after the jitted unpack, so conformance
+                # stays bitwise)
+                stage = np.zeros((6, b), np.int32)
+                stage_temps = stage[_ROW_TEMP].view(np.float32)
+                probing = 0
+                for i in active_ids:
+                    s = self.slots[i]
+                    probe = probe_flag(s.steps, interval, self.scfg.seed)
+                    probing += probe
+                    stage[_ROW_TOK, i] = s.generated[-1]
+                    stage[_ROW_PROBE, i] = probe
+                    stage[_ROW_ACT, i] = 1
+                    stage_temps[i] = s.request.sampling.temperature
+                    stage[_ROW_SEED, i] = s.request.sampling.seed
+                    stage[_ROW_CTR, i] = len(s.generated)
+                tok, probes, act, temps, seeds, counters = self._unstage(
+                    jnp.asarray(stage))  # sync: ok(the single batched host->device staging transfer per step)
+            n["probe_rows"] += probing
+            n["probe_steps"] += probing > 0
+
+            with tracing.span("engine.decode"):
+                logits, self.caches = self._decode_masked(
+                    self.params, self.caches, tok, probes, act)
+            with tracing.span("engine.readback"):
+                nxt, finite = np.asarray(  # sync: ok(the single batched device->host token read per step)
+                    self._sample(logits, temps, seeds, counters))
+            self.nonfinite_logits += int(  # sync: ok(host numpy: the readback above)
+                np.sum(finite[active_ids] == 0))
+
+            due = []
+            with tracing.span("engine.emit"):
+                for i in active_ids:
+                    s = self.slots[i]
+                    s.steps += 1
+                    s.since_rc += 1
+                    s.generated.append(int(nxt[i]))
+                    self._emit_token(s.request, int(nxt[i]),
+                                     len(s.generated) - 1)
+                    if self._maybe_finish(i):
+                        continue
+                    if s.since_rc >= interval:
+                        due.append(i)
+            if due:
+                with tracing.span("engine.fold", slots=len(due)):
+                    self._fold(due)
+                for i in due:
+                    self.slots[i].since_rc = 0
+            self._step_no += 1
             events, self._events = self._events, []
             return events
-        interval = self.ccfg.recompress_interval
-        if self._alloc is not None:
-            for i in active_ids:
-                self._alloc.note_append(i)
-            self._sync_tables()
-
-        # all per-slot scalars ride ONE (6, b) staging matrix: a single
-        # host->device transfer per step instead of six (the hostsync lint
-        # flags per-scalar churn; values/dtypes are bit-identical after the
-        # jitted unpack, so conformance stays bitwise)
-        stage = np.zeros((6, b), np.int32)
-        stage_temps = stage[_ROW_TEMP].view(np.float32)
-        for i in active_ids:
-            s = self.slots[i]
-            stage[_ROW_TOK, i] = s.generated[-1]
-            stage[_ROW_PROBE, i] = probe_flag(s.steps, interval, self.scfg.seed)
-            stage[_ROW_ACT, i] = 1
-            stage_temps[i] = s.request.sampling.temperature
-            stage[_ROW_SEED, i] = s.request.sampling.seed
-            stage[_ROW_CTR, i] = len(s.generated)
-        tok, probes, act, temps, seeds, counters = self._unstage(
-            jnp.asarray(stage))  # sync: ok(the single batched host->device staging transfer per step)
-
-        logits, self.caches = self._decode_masked(
-            self.params, self.caches, tok, probes, act)
-        nxt, finite = np.asarray(  # sync: ok(the single batched device->host token read per step)
-            self._sample(logits, temps, seeds, counters))
-        self.nonfinite_logits += int(  # sync: ok(host numpy: the readback above)
-            np.sum(finite[active_ids] == 0))
-
-        due = []
-        for i in active_ids:
-            s = self.slots[i]
-            s.steps += 1
-            s.since_rc += 1
-            s.generated.append(int(nxt[i]))
-            self._emit_token(s.request, int(nxt[i]), len(s.generated) - 1)
-            if self._maybe_finish(i):
-                continue
-            if s.since_rc >= interval:
-                due.append(i)
-        if due:
-            self._fold(due)
-            for i in due:
-                self.slots[i].since_rc = 0
-        self._step_no += 1
-        events, self._events = self._events, []
-        return events
 
 
 class ContinuousEngine(EngineCore):

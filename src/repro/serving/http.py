@@ -21,7 +21,11 @@ Endpoints
                         (the per-token events concatenate to exactly it).
                         stream=false -> one JSON response when finished.
     POST /v1/cancel     {"id": rid} -> {"cancelled": bool}.
-    GET  /v1/stats      engine/router load + pool telemetry as JSON.
+    GET  /v1/stats      engine/router load + pool telemetry as JSON, and
+                        the work counters: {"pool_stats", "engine"
+                        (`EngineCore.counters()`, per replica under a
+                        router), "front" (`HttpFrontend.counters()`),
+                        "replicas" (router only)}.
     GET  /health        liveness probe.
 
 Drive loop
@@ -59,6 +63,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro.runtime import tracing
 from repro.serving import engine as engine_lib
 from repro.serving import events as events_lib
 
@@ -129,6 +134,9 @@ class HttpFrontend:
     sessions so jit caches stay warm and steady state stays retrace-free).
     """
 
+    COUNTERS = ("drive_steps", "tokens_routed", "tokens_written",
+                "backlog_token_steps")
+
     def __init__(self, engine, host: str = "127.0.0.1", port: int = 0,
                  backoff: Optional[Backoff] = None):
         self.engine = engine
@@ -140,6 +148,21 @@ class HttpFrontend:
         self._drive_task: Optional[asyncio.Task] = None
         self._closed = False
         self._error: Optional[BaseException] = None   # a failed step()
+        # per open SSE stream: tokens routed to it but not yet written
+        self._unwritten: Dict[str, int] = {}
+        self._counts = dict.fromkeys(self.COUNTERS, 0)
+        tracing.install_gc_span()
+
+    def counters(self) -> Dict[str, int]:
+        """Cumulative counts since construction, served as `front` by
+        `/v1/stats`: `drive_steps` (engine steps the drive loop took),
+        `tokens_routed` (token events put on a request's queue),
+        `tokens_written` (SSE token frames written), and
+        `backlog_token_steps`: at each drive step, before `step()`, the
+        tokens routed to open SSE streams but not yet written, summed over
+        the streams, added up over steps.  Each `front.route` span carries
+        them as they stand when routing begins."""
+        return dict(self._counts)
 
     # ------------------------------------------------------------------
     # drive loop: the ONLY caller of engine.step() while the front is up
@@ -148,11 +171,19 @@ class HttpFrontend:
     def _drive_once(self) -> bool:
         """One engine step; route its events to the waiting handlers.
         Returns True if the step produced any events (progress)."""
+        n = self._counts
+        n["drive_steps"] += 1
+        n["backlog_token_steps"] += sum(self._unwritten.values())
         events = self.engine.step()
-        for ev in events:
-            q = self._queues.get(ev.request_id)
-            if q is not None:
-                q.put_nowait(ev)
+        with tracing.span("front.route", events=len(events), **n):
+            for ev in events:
+                q = self._queues.get(ev.request_id)
+                if q is not None:
+                    q.put_nowait(ev)
+                    if isinstance(ev, events_lib.TokenEvent):
+                        n["tokens_routed"] += 1
+                        if ev.request_id in self._unwritten:
+                            self._unwritten[ev.request_id] += 1
         if events:
             self.backoff.reset()
             return True
@@ -269,7 +300,9 @@ class HttpFrontend:
             writer.write(_json_response("200 OK", {"ok": True}))
             await writer.drain()
         elif method == "GET" and target == "/v1/stats":
-            stats = {"pool_stats": self.engine.pool_stats()}
+            stats = {"pool_stats": self.engine.pool_stats(),
+                     "engine": self.engine.counters(),
+                     "front": self.counters()}
             router_stats = getattr(self.engine, "stats", None)
             if callable(router_stats):
                 stats["replicas"] = router_stats()
@@ -344,14 +377,18 @@ class HttpFrontend:
         if self._error is not None:
             queue.put_nowait(self._error)   # accepted after the failure
         self._queues[rid] = queue
+        stream = spec.get("stream", True)
+        if stream:
+            self._unwritten[rid] = 0
         self._wake.set()
         try:
-            if spec.get("stream", True):
+            if stream:
                 await self._stream_sse(rid, queue, reader, writer)
             else:
                 await self._respond_json(rid, queue, writer)
         finally:
             self._queues.pop(rid, None)
+            self._unwritten.pop(rid, None)
 
     def _final_payload(self, rid: str) -> Dict:
         out = self.engine.result(rid)
@@ -402,8 +439,12 @@ class HttpFrontend:
                     return
                 if isinstance(ev, events_lib.TokenEvent):
                     try:
-                        writer.write(_sse(
-                            {"token": ev.token, "index": ev.index}))
+                        with tracing.span("front.sse_write", request_id=rid,
+                                          index=ev.index):
+                            writer.write(_sse(
+                                {"token": ev.token, "index": ev.index}))
+                        self._unwritten[rid] -= 1
+                        self._counts["tokens_written"] += 1
                         await writer.drain()
                     except (ConnectionResetError, BrokenPipeError):
                         self._cancel_quietly(rid, "client")

@@ -5,7 +5,7 @@ Scaling past a single replica's slot count means running N engines and
 deciding, per request, WHICH one admits it — the jetstream-style
 environment/engine split (ROADMAP item 1).  `EngineRouter` is that layer:
 it duck-types the `EngineCore` request API (`submit` / `cancel` / `poll` /
-`result` / `stream` / `step` / `run` / `pending` / `shutdown` /
+`result` / `stream` / `step` / `run` / `pending` / `shutdown` / `counters` /
 `pool_stats`) so every existing driver — the HTTP front, `stream()`
 consumers, the benchmarks — works unchanged against 1 or N replicas.
 
@@ -276,6 +276,12 @@ class EngineRouter:
         """Per-replica pool telemetry, keyed by replica name (each value is
         that engine's `pool_stats()` — None for static/mixed layouts)."""
         return {name: eng.pool_stats()
+                for name, eng in zip(self.names, self.replicas)}
+
+    def counters(self) -> Dict[str, Dict[str, int]]:
+        """Per-replica work counters, keyed by replica name (each value is
+        that engine's `counters()`)."""
+        return {name: eng.counters()
                 for name, eng in zip(self.names, self.replicas)}
 
     def stats(self) -> Dict[str, Dict]:
