@@ -36,8 +36,19 @@ by (8, 128) or to equal the array's.  So the per-page operands and outputs
 carry the page axis OUTSIDE their last two dims (pos (b,npp,1,page), p
 (b,hk,npp,g,page), m_run (b,hk,npp,g,1)) and the wrapper reshapes them;
 packed codes unpack in int32 lanes (Mosaic has no uint8 arithmetic).
-tests/test_tpu_compile.py compiles the kernel for a described v5e chip at
-yi-6b widths; CI runs it in interpret mode on the CPU.
+Codes unpack in PLANE order (`plane_perm`): field j of every byte becomes
+one contiguous block of d/pf lanes, so position j*(d/pf) + i holds channel
+i*pf + j.  Interleaving the fields back into channel order inside the
+kernel is a lane interleave, which Mosaic lowers into ~13,000 shuffle,
+rotate and select ops per page, about 20x the attention math; whole planes
+join with none.  The permutation lives in `qattn_paged_segment`: it carries
+the per-slot operands on the channel axes the codes meet (q, k_scale,
+k_zero along K's contraction; v_cscale along V's channels) into plane order
+before the call and returns acc to channel order after it, so its contract
+stays in channel order.  tests/test_tpu_compile.py compiles the kernel for a
+described v5e chip at yi-6b widths and bounds each quantized segment's
+kernel body by twice the raw window segment's op count; CI runs the kernel
+in interpret mode on the CPU.
 """
 
 from __future__ import annotations
@@ -46,6 +57,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -56,9 +68,25 @@ NEG_INF = -1e30
 _F32 = jax.lax.Precision.HIGHEST
 
 
-def _unpack(codes, bits, d):
-    """codes (S, d//pf) -> (S, d) f32 via shift/mask (lane-dim packing).
+def plane_perm(bits: int, d: int):
+    """Channel held at each lane of a `d`-wide row that `_unpack` returns.
 
+    With pf = 8 // bits, core/packing.py stores channel i*pf + j in field j
+    of byte i; `_unpack` lays field j of every byte out as one contiguous
+    plane, so plane-order position j*(d/pf) + i holds channel i*pf + j.
+    None where unpacking keeps channel order (raw segments, 8-bit codes)."""
+    if bits >= 16 or bits == 8:
+        return None
+    pf = 8 // bits
+    return np.arange(d).reshape(d // pf, pf).T.reshape(-1)
+
+
+def _unpack(codes, bits):
+    """codes (S, d//pf) -> (S, d) f32 via shift/mask, in PLANE order.
+
+    Field j of every byte fills lanes [j*d/pf, (j+1)*d/pf) (`plane_perm`):
+    joining whole planes costs no lane shuffles, where interleaving the
+    fields back into channel order costs thousands per page.
     bits >= 16: raw segment — pages hold values already, pass through."""
     if bits >= 16:
         return codes.astype(jnp.float32)
@@ -67,9 +95,8 @@ def _unpack(codes, bits, d):
     if pf == 1:
         return w.astype(jnp.float32)
     mask = 2**bits - 1
-    fields = [(w >> (i * bits)) & mask for i in range(pf)]
-    return jnp.stack(fields, axis=-1).reshape(codes.shape[0], d) \
-        .astype(jnp.float32)
+    fields = [(w >> (j * bits)) & mask for j in range(pf)]
+    return jnp.concatenate(fields, axis=-1).astype(jnp.float32)
 
 
 def _paged_qattn_kernel(tbl_ref,  # scalar prefetch: (b, npp) page table
@@ -78,7 +105,7 @@ def _paged_qattn_kernel(tbl_ref,  # scalar prefetch: (b, npp) page table
                         acc_out, m_out, l_out, p_out, mrun_out,
                         acc_ref, m_ref, l_ref,
                         *, scale: float, k_bits: int, v_bits: int,
-                        d: int, dv: int, k_dtype, v_dtype):
+                        k_dtype, v_dtype):
     j = pl.program_id(2)
     nj = pl.num_programs(2)
 
@@ -89,7 +116,7 @@ def _paged_qattn_kernel(tbl_ref,  # scalar prefetch: (b, npp) page table
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)                 # (g, d)
-    k = _unpack(kc_ref[0, 0], k_bits, d)                # (page, d)
+    k = _unpack(kc_ref[0, 0], k_bits)                   # (page, d)
     k = (k - kz_ref[0, 0, 0].astype(jnp.float32)[None, :]) \
         * ks_ref[0, 0, 0].astype(jnp.float32)[None, :]
     if k_bits < 16:  # dense ref rounds dequantized values to the store dtype
@@ -106,7 +133,7 @@ def _paged_qattn_kernel(tbl_ref,  # scalar prefetch: (b, npp) page table
     p_out[0, 0, 0] = p                                  # relative to m_new
     mrun_out[0, 0, 0] = m_new
 
-    v = _unpack(vc_ref[0, 0], v_bits, dv)               # (page, dv)
+    v = _unpack(vc_ref[0, 0], v_bits)                   # (page, dv)
     v = (v - vtz_ref[0, 0].astype(jnp.float32)) * vts_ref[0, 0].astype(jnp.float32)
     v = v * vcs_ref[0, 0, 0].astype(jnp.float32)[None, :]
     if v_bits < 16:
@@ -150,11 +177,20 @@ def qattn_paged_segment(q, k_pages, k_scale, k_zero, v_pages, v_cscale,
     npp = table.shape[1]
     dv = v_cscale.shape[-1]
     g = h // hk
+    # the kernel unpacks codes in plane order: carry K's contraction axis
+    # (q, k_scale, k_zero) and V's channel axis (v_cscale) into it, and
+    # bring acc back to channel order after the call
+    k_perm, v_perm = plane_perm(k_bits, d), plane_perm(v_bits, dv)
+    if k_perm is not None:
+        q, k_scale, k_zero = q[..., k_perm], k_scale[..., k_perm], \
+            k_zero[..., k_perm]
+    if v_perm is not None:
+        v_cscale = v_cscale[..., v_perm]
     q4 = q.reshape(b, hk, g, d)
     grid = (b, hk, npp)
     kernel = functools.partial(
         _paged_qattn_kernel, scale=scale, k_bits=k_bits, v_bits=v_bits,
-        d=d, dv=dv, k_dtype=k_dtype, v_dtype=v_dtype)
+        k_dtype=k_dtype, v_dtype=v_dtype)
     ck = k_pages.shape[-1]
     cv = v_pages.shape[-1]
     s_pad = npp * page
@@ -207,4 +243,6 @@ def qattn_paged_segment(q, k_pages, k_scale, k_zero, v_pages, v_cscale,
     p = jnp.swapaxes(p, 2, 3).reshape(b, h, s_pad)
     mrun = jnp.broadcast_to(mrun, (b, hk, npp, g, page))
     mrun = jnp.swapaxes(mrun, 2, 3).reshape(b, h, s_pad)
+    if v_perm is not None:
+        acc = acc[..., np.argsort(v_perm)]
     return acc.reshape(b, h, dv), m.reshape(b, h), l.reshape(b, h), p, mrun

@@ -28,7 +28,9 @@ import pytest
 
 from repro.core import backend as backend_lib
 from repro.core import kvcache as kvc
+from repro.core import packing
 from repro.core.policy import CompressionConfig
+from repro.kernels.paged_qattn import kernel as pq_kernel
 from repro.kernels.paged_qattn import ops as pq_ops
 
 QUANT_TOL = 0.35  # 4/2-bit mixed policy vs float reference (test_kvcache.py)
@@ -62,13 +64,32 @@ def _ragged_cache(be, rng, lengths, hk, d, max_len, n_append=2,
     return cache
 
 
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("bits", [2, 4, 8])
+def test_unpack_returns_plane_order(bits, d, rng):
+    """The kernel unpacks a page's codes into plane order: field j of every
+    byte as one contiguous block of lanes, so position j*(d/pf) + i holds
+    channel i*pf + j.  8-bit codes keep channel order."""
+    codes = rng.integers(0, 2**bits, size=(16, d))
+    perm = pq_kernel.plane_perm(bits, d)
+    if perm is None:
+        assert bits == 8
+        perm = np.arange(d)
+    assert sorted(perm) == list(range(d))
+    got = pq_kernel._unpack(packing.pack(jnp.asarray(codes), bits), bits)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  codes[..., perm].astype(np.float32))
+
+
 @pytest.mark.parametrize("page_size", [8, 16, 64])
-@pytest.mark.parametrize("heads", [(4, 2), (4, 4), (8, 1)])  # GQA, MHA, MQA
+@pytest.mark.parametrize("heads", [(4, 2), (4, 4), (8, 1), (14, 2)])
 def test_paged_kernel_matches_gather_and_ref(page_size, heads, rng):
     """Sweep: kernel == oracle == gather+dense on ragged batches including a
-    length-0 slot and partially-filled last pages."""
+    length-0 slot and partially-filled last pages, over GQA, MHA, MQA and
+    qwen2-7b's query group of 7 at its head dim."""
     h, hk = heads
-    d, max_len = 16, 60  # capacities not page multiples for pages 16/64
+    d = 128 if h == 14 else 16
+    max_len = 60  # capacities not page multiples for pages 16/64
     be = backend_lib.of(_ccfg(saliency_ratio=0.4), kind="paged",
                         page_size=page_size)
     lengths = [48, 0, 17, 33]
