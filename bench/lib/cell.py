@@ -42,15 +42,19 @@ def schedule(res: dict) -> dict:
 
 
 def window_work(res: dict, c: dict) -> dict:
-    """Kernel FLOPs/bytes and model FLOPs of the window's steps, from the
-    live context of each slot (prompt, tokens decoded, window fill)."""
+    """FLOPs/bytes of each of the adapter's kernels and model FLOPs of the
+    window's steps, from the live context of each slot (prompt, tokens
+    decoded, window fill)."""
     policy = c["config"]["policy"]
     model = c["config"]["model"]
     interval = policy["recompress_interval"]
     sched = schedule(res)
-    kmod = spec.module("counts", "paged_qattn")
+    kmods = {k: spec.module("counts", k) for k in
+             spec.module("adapters", c["config"]["reference"]).KERNELS}
     mmod = spec.module("counts", c["config"]["reference"])
-    kf = kb = mf = 0
+    kernels = {k: {"flops": 0, "bytes": 0, "op_names": list(m.OP_NAMES)}
+               for k, m in kmods.items()}
+    mf = 0
     folds = 0
     for s in res["window_steps"]:
         slots = []
@@ -62,12 +66,11 @@ def window_work(res: dict, c: dict) -> dict:
             slots.append((ctx - win, win))
             mf += mmod.token_flops(model, ctx)
             folds += win == interval
-        f, b = kmod.step(model, policy, slots)
-        kf += f
-        kb += b
-    return {"kernel": {"flops": kf, "bytes": kb,
-                       "op_names": list(kmod.OP_NAMES)},
-            "model_flops": mf, "folds": folds}
+        for k, m in kmods.items():
+            f, b = m.step(model, policy, slots)
+            kernels[k]["flops"] += f
+            kernels[k]["bytes"] += b
+    return {"kernels": kernels, "model_flops": mf, "folds": folds}
 
 
 def probe_steps(res: dict, c: dict) -> int:
@@ -95,14 +98,15 @@ def run(c: dict, seed: int, seconds: float, trace: bool, log, t_start: float,
     model = c["config"]["model"]
     traffic = c["traffic"]
     traffic_lib.validate(traffic)
+    arch = spec.module("adapters", c["config"]["reference"])
     t = time.perf_counter()
-    w = weights_lib.make(model, seed, device)
+    w = weights_lib.make(arch.leaves(model), seed, device)
     jax.block_until_ready(w)
     log(f"set-up: weights on the device from the seed: "
         f"{time.perf_counter() - t:.2f}s")
     t = time.perf_counter()
     engine = served.build_engine(c["config"], traffic,
-                                 weights_lib.to_program(w), device)
+                                 arch.to_program(w, model), device)
     _policy_matches(engine, c["config"]["policy"])
     log(f"set-up: engine build: {time.perf_counter() - t:.2f}s")
     sessions = traffic_lib.sessions(traffic, seed, model["vocab_size"])
@@ -168,7 +172,8 @@ def run(c: dict, seed: int, seconds: float, trace: bool, log, t_start: float,
                 "traffic": traffic, "config": c["config"],
                 "cache_bytes": res["cache_bytes"],
                 "context_tokens": res["context_tokens"],
-                "kernels": {"paged_qattn": work["kernel"]},
+                "kv_values_per_token": arch.kv_values_per_token(model),
+                "kernels": work["kernels"],
                 "model_flops": work["model_flops"], "trace": None,
                 "peaks": None}
     if not trace:

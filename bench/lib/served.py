@@ -26,6 +26,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from . import spec
 from . import window as window_lib
 
 POLL_S = 0.002
@@ -174,27 +175,11 @@ async def client(port: int, sessions, record: list, failures: list) -> None:
 # build
 # ---------------------------------------------------------------------------
 
-def arch_config(model: dict, name: str):
-    """The program's ArchConfig for a configuration file's model block."""
-    from repro.configs.base import ArchConfig
-    if model["hidden_size"] != model["num_attention_heads"] * model["head_dim"]:
-        raise ValueError("the program derives head_dim as hidden_size / "
-                         "num_attention_heads; the file disagrees")
-    return ArchConfig(
-        name=name, family="dense", n_layers=model["num_hidden_layers"],
-        d_model=model["hidden_size"], n_heads=model["num_attention_heads"],
-        n_kv_heads=model["num_key_value_heads"],
-        d_ff=model["intermediate_size"], vocab=model["vocab_size"],
-        qkv_bias=bool(model["qkv_bias"]),
-        norm_eps=float(model["rms_norm_eps"]),
-        rope_theta=float(model["rope_theta"]),
-        tie_embeddings=bool(model["tie_word_embeddings"]))
-
-
 def build_engine(config: dict, traffic: dict, params, device):
     """ContinuousEngine from the program's own engine flags (the
     configuration file's `program.argv` plus the traffic's static shapes),
-    validated as the HTTP server validates them."""
+    validated as the HTTP server validates them, and the ArchConfig of the
+    configuration's adapter (`bench/adapters/<reference>.py`)."""
     from repro.launch import serve as serve_cli
     from repro.launch import serve_http
     from repro.serving import ContinuousEngine
@@ -204,7 +189,8 @@ def build_engine(config: dict, traffic: dict, params, device):
         "--prompt-len", str(traffic["prompt_len"]),
         "--max-new", str(traffic["max_new"])])
     serve_cli.validate_engine_args(args, ap, continuous=True)
-    cfg = arch_config(config["model"], config["name"])
+    cfg = spec.module("adapters", config["reference"]).arch_config(
+        config["model"], config["name"])
     ccfg = serve_cli.build_compression_config(args)
     scfg = serve_cli.build_serve_config(args)
     return ContinuousEngine(cfg, ccfg, scfg, params, device=device)

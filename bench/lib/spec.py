@@ -3,10 +3,16 @@
     bench/configs/<config>.json     a configuration: sizes, source, cuts,
                                     the program's engine flags, the policy
     bench/refs/<reference>.py       its plain reference (named in the file)
+    bench/adapters/<reference>.py   all the harness knows of the architecture:
+                                    weight layout, the engine's parameter
+                                    tree and ArchConfig, kernels, K/V values
+                                    per token
+    bench/counts/<reference>.py     its model FLOPs per token, for `mfu`
     bench/traffic/<traffic>.json    a traffic mix's parameters
     bench/limits/<workload>.json    each compared number's limit
     bench/metrics/<metric>.py       a per-layer metric's reader: read(run)
-    bench/counts/<kernel>.py        a kernel's (or model's) FLOPs and bytes
+    bench/counts/<kernel>.py        a kernel's FLOPs and bytes (one per name
+                                    in the adapter's KERNELS)
 """
 
 from __future__ import annotations

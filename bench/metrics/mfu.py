@@ -1,7 +1,7 @@
 """The whole step's share of the chip's bf16 peak: model FLOPs of every
-token generated in the window (`bench/counts/dense_gqa`: 2 x matmul
-parameters + attention over each token's context) over the window's
-length times the peak."""
+token generated in the window (the configuration's `counts/<reference>`:
+FLOPs per token at its context) over the window's length times the
+peak."""
 
 
 def read(run):

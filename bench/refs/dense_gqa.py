@@ -6,7 +6,7 @@ and optional q/k/v biases, residual, RMSNorm, SwiGLU MLP, residual; final
 RMSNorm and an untied LM head.  Straight `jax.numpy` over the whole
 sequence with a causal mask: no cache, no quantization, no kernels, no
 batching.  It imports nothing of the program under test and reads only the
-benchmark's own weights (`bench/lib/weights.py` layout).
+benchmark's own weights (`bench/adapters/dense_gqa.py` layout).
 
 `precision="f32"` is the reference: float32 activations, every matmul at
 `Precision.HIGHEST` (the TPU's default would round f32 operands to bf16).

@@ -36,9 +36,11 @@ def tiny_run(tmp_path_factory):
     c, seed = tiny.cell(), 5
     trace_dir = tmp_path_factory.mktemp("trace")
     device = jax.devices()[0]
-    w = weights_lib.make(c["config"]["model"], seed, device)
+    model = c["config"]["model"]
+    arch = spec.module("adapters", c["config"]["reference"])
+    w = weights_lib.make(arch.leaves(model), seed, device)
     engine = served.build_engine(c["config"], c["traffic"],
-                                 weights_lib.to_program(w), device)
+                                 arch.to_program(w, model), device)
     sessions = traffic_lib.sessions(c["traffic"], seed,
                                     c["config"]["model"]["vocab_size"])
     res = asyncio.run(served.serve_window(engine, c["traffic"], sessions,

@@ -1,5 +1,6 @@
 """BENCHMARK.json keeps to its allowed characters, and every cell's
-configuration, traffic, limits and metric readers resolve by name."""
+configuration, reference, adapter, kernel counts, traffic, limits and
+metric readers resolve by name."""
 
 import json
 
@@ -43,6 +44,13 @@ def test_cell_files_resolve(w):
     assert c["config"]["name"] == w["config"]
     spec.module("refs", c["config"]["reference"])
     spec.module("counts", c["config"]["reference"])
+    arch = spec.module("adapters", c["config"]["reference"])
+    for f in ("leaves", "to_program", "arch_config", "kv_values_per_token"):
+        assert callable(getattr(arch, f)), f
+    assert arch.KERNELS
+    for k in arch.KERNELS:
+        kmod = spec.module("counts", k)
+        assert kmod.OP_NAMES and callable(kmod.step)
     for m in c["per_layer"]:
         assert callable(spec.module("metrics", m["name"]).read)
     assert set(c["limits"]) <= {"gap_max", "gap_mean"} and c["limits"]
